@@ -350,9 +350,10 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.context import CheckpointConfig, CheckpointContext
+    from repro.dist.context import make_mesh
 
     repeats = max(int(sys.argv[1]), 5)
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     n = 1 << 12                       # 4096x4096 f32 = 64 MiB of payload
     host = np.arange(n * n, dtype=np.float32).reshape(n, n)
     sh = NamedSharding(mesh, P("data", "model"))
@@ -396,7 +397,9 @@ def sharded_store(repeats: int = 3) -> Dict[str, float]:
     sharded path must not be slower — it moves the same bytes but skips
     the global host buffer and writes chunks in parallel.  Runs in a
     subprocess (device count locks at jax init)."""
-    env = dict(os.environ,
+    # forced host devices: the child must never reach for an accelerator
+    # (this parent may already hold it)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH", ""))
     r = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT, str(repeats)],
                        capture_output=True, text=True, timeout=900, env=env)

@@ -704,15 +704,22 @@ def run_matrix(workdir: str,
 
     Supervised scenarios spawn real worker processes, so they run once
     (first backend) instead of per matrix cell, and only when named
-    explicitly or requested via *include_supervised*."""
+    explicitly or requested via *include_supervised*.  They run before
+    every in-process scenario: those initialize a JAX backend in this
+    process, and on an accelerator host that backend holds the chip the
+    spawned workers need."""
     names = list(names or SCENARIOS)
     if include_supervised:
         names += [n for n in SUPERVISED if n not in names]
+    supervised = {
+        n: run_scenario(n, backends[0],
+                        os.path.join(workdir, f"{n}-{backends[0]}"),
+                        trace_dir)
+        for n in names if n in SUPERVISED}
     results = []
     for n in names:
-        if n in SUPERVISED:
-            d = os.path.join(workdir, f"{n}-{backends[0]}")
-            results.append(run_scenario(n, backends[0], d, trace_dir))
+        if n in supervised:
+            results.append(supervised[n])
             continue
         for be in backends:
             d = os.path.join(workdir, f"{n}-{be}")

@@ -29,13 +29,24 @@ import threading
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 DATA = "data"
 MODEL = "model"
 POD = "pod"
 
 _state = threading.local()
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence] = None) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes — every mesh in this repo is
+    built here.  The hint layer constrains with ``with_sharding_constraint``
+    and lets GSPMD propagate the rest, which ``Explicit`` axes (the
+    ``jax.make_mesh`` default since jax 0.7) refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def _st():

@@ -23,10 +23,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 _NEG_INF = -1e30
@@ -103,7 +99,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),     # running denominator
             pltpu.VMEM((bq, dh), jnp.float32),    # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -1,8 +1,15 @@
-"""Jit'd public wrappers for the checkpoint kernels.
+"""Public wrappers for the checkpoint kernels.
 
 Dispatch: Pallas kernels on TPU; vectorized jnp oracle (ref.py) on CPU —
 so the diff engine runs everywhere, and tests can force the Pallas path in
 ``interpret=True`` mode to validate the kernels bit-exactly against ref.
+On TPU there is no fallback: a block size the kernels cannot tile raises.
+
+On TPU a leaf's block table is split by rows over the devices that hold
+the leaf (``shard_map``): a Mosaic kernel cannot be partitioned by the
+compiler, and every block hashes or packs independently, so each device
+runs the kernel on its own rows.  A single-device leaf is the one-device
+case of the same program.
 """
 from __future__ import annotations
 
@@ -12,22 +19,26 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.dist.context import make_mesh
 from repro.kernels import blockhash as bh
 from repro.kernels import diffpack as dp
 from repro.kernels import ref
 
 DEFAULT_BLOCK_BYTES = 65_536      # 64 KiB — FTI dCP-scale block granularity
+ROW_AXIS = "blocks"
 
 
 def _use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def as_u32_blocks(x: jnp.ndarray, block_bytes: int = DEFAULT_BLOCK_BYTES
-                  ) -> Tuple[jnp.ndarray, int]:
+def as_u32_blocks(x: jnp.ndarray, block_bytes: int = DEFAULT_BLOCK_BYTES,
+                  row_multiple: int = 1) -> Tuple[jnp.ndarray, int]:
     """Bitcast any array to (n_blocks, block_elems) uint32, zero-padded.
-    Returns (blocks, n_blocks). Pads so the Pallas tile grid divides evenly."""
+    Returns (blocks, n_blocks); the row count is padded up to a multiple
+    of ``row_multiple`` so a kernel's tile grid divides evenly."""
     assert block_bytes % 4 == 0
     be = block_bytes // 4
     flat = x.reshape(-1)
@@ -55,34 +66,101 @@ def as_u32_blocks(x: jnp.ndarray, block_bytes: int = DEFAULT_BLOCK_BYTES
         raise TypeError(f"unsupported dtype {x.dtype}")
     n = flat.shape[0]
     n_blocks = max(1, -(-n // be))
-    pad_rows = (-n_blocks) % bh.BR if _use_pallas() else 0
-    total = (n_blocks + pad_rows) * be
-    flat = jnp.pad(flat, (0, total - n))
-    return flat.reshape(n_blocks + pad_rows, be), n_blocks
+    rows = n_blocks + (-n_blocks) % row_multiple
+    flat = jnp.pad(flat, (0, rows * be - n))
+    return flat.reshape(rows, be), n_blocks
 
 
-@functools.partial(jax.jit, static_argnames=("block_bytes",))
-def blockhash(x: jnp.ndarray, block_bytes: int = DEFAULT_BLOCK_BYTES
-              ) -> jnp.ndarray:
-    """Array → (n_blocks, 2) uint32 digest (64-bit per block)."""
-    blocks, n_blocks = as_u32_blocks(x, block_bytes)
-    if _use_pallas() and blocks.shape[1] % bh.BE == 0:
-        h = bh.blockhash2_pallas(blocks)
+def row_mesh(x) -> jax.sharding.Mesh:
+    """1-D mesh over the devices holding ``x`` (the default device for a
+    host array): the devices its block table is split across."""
+    sharding = getattr(x, "sharding", None)
+    if sharding is not None:
+        devices = sorted(sharding.device_set, key=lambda d: d.id)
     else:
-        h = ref.blockhash2_ref(blocks)
+        devices = jax.devices()[:1]
+    return make_mesh((len(devices),), (ROW_AXIS,), devices=devices)
+
+
+def _row_blocks(x, block_bytes: int, mesh) -> Tuple[jnp.ndarray, int]:
+    blocks, n_blocks = as_u32_blocks(x, block_bytes,
+                                     row_multiple=bh.BR * mesh.size)
+    return jax.lax.with_sharding_constraint(
+        blocks, NamedSharding(mesh, P(ROW_AXIS, None))), n_blocks
+
+
+# --------------------------------------------------------------------------- #
+# blockhash
+# --------------------------------------------------------------------------- #
+
+
+@functools.partial(jax.jit, static_argnames=("block_bytes", "mesh",
+                                             "interpret"))
+def blockhash_pallas(x: jnp.ndarray, block_bytes: int, mesh,
+                     interpret: bool = False) -> jnp.ndarray:
+    """TPU path: rows of the block table hashed where they live."""
+    blocks, n_blocks = _row_blocks(x, block_bytes, mesh)
+    h = jax.shard_map(functools.partial(bh.blockhash2_pallas,
+                                        interpret=interpret), mesh=mesh,
+                      in_specs=P(ROW_AXIS, None), out_specs=P(ROW_AXIS, None),
+                      check_vma=False)(blocks)
     return h[:n_blocks]
 
 
-@functools.partial(jax.jit, static_argnames=("block_bytes", "n_dirty"))
+@functools.partial(jax.jit, static_argnames=("block_bytes",))
+def _blockhash_ref(x: jnp.ndarray, block_bytes: int) -> jnp.ndarray:
+    blocks, n_blocks = as_u32_blocks(x, block_bytes)
+    return ref.blockhash2_ref(blocks)[:n_blocks]
+
+
+def blockhash(x: jnp.ndarray, block_bytes: int = DEFAULT_BLOCK_BYTES
+              ) -> jnp.ndarray:
+    """Array → (n_blocks, 2) uint32 digest (64-bit per block)."""
+    if _use_pallas():
+        return blockhash_pallas(x, block_bytes, row_mesh(x))
+    return _blockhash_ref(x, block_bytes)
+
+
+# --------------------------------------------------------------------------- #
+# pack_dirty
+# --------------------------------------------------------------------------- #
+
+
+@functools.partial(jax.jit, static_argnames=("n_dirty", "block_bytes",
+                                             "mesh", "interpret"))
+def pack_dirty_pallas(x: jnp.ndarray, dirty_idx: jnp.ndarray, n_dirty: int,
+                      block_bytes: int, mesh, interpret: bool = False
+                      ) -> jnp.ndarray:
+    """TPU path: each device packs the dirty blocks it holds (zeros for
+    the rest) and the partial packs sum across devices."""
+    blocks, _ = _row_blocks(x, block_bytes, mesh)
+
+    def local(rows, idx):
+        lo = jax.lax.axis_index(ROW_AXIS) * rows.shape[0]
+        return jax.lax.psum(dp.diffpack_pallas(rows, idx, row_offset=lo,
+                                               interpret=interpret),
+                            ROW_AXIS)
+
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(ROW_AXIS, None), P()), out_specs=P(),
+                         check_vma=False)(blocks, dirty_idx[:n_dirty])
+
+
+@functools.partial(jax.jit, static_argnames=("n_dirty", "block_bytes"))
+def _pack_dirty_ref(x: jnp.ndarray, dirty_idx: jnp.ndarray, n_dirty: int,
+                    block_bytes: int) -> jnp.ndarray:
+    blocks, _ = as_u32_blocks(x, block_bytes)
+    return ref.diffpack_ref(blocks, dirty_idx[:n_dirty])
+
+
 def pack_dirty(x: jnp.ndarray, dirty_idx: jnp.ndarray, n_dirty: int,
                block_bytes: int = DEFAULT_BLOCK_BYTES) -> jnp.ndarray:
     """Gather ``n_dirty`` blocks (static count — pad idx with 0s and slice
     host-side) → (n_dirty, block_elems) uint32."""
-    blocks, _ = as_u32_blocks(x, block_bytes)
-    idx = dirty_idx[:n_dirty]
     if _use_pallas():
-        return dp.diffpack_pallas(blocks, idx)
-    return ref.diffpack_ref(blocks, idx)
+        return pack_dirty_pallas(x, dirty_idx, n_dirty, block_bytes,
+                                 row_mesh(x))
+    return _pack_dirty_ref(x, dirty_idx, n_dirty, block_bytes)
 
 
 def dirty_indices(h_new: np.ndarray, h_old: Optional[np.ndarray]) -> np.ndarray:

@@ -1,13 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"     # placeholder host devices, never a chip
 
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 production mesh and extract memory/cost/collective analyses for §Dry-run
 and §Roofline of EXPERIMENTS.md.
 
-The two lines above MUST precede any jax-importing import: jax locks the
+The lines above MUST precede any jax-importing import: jax locks the
 device count at first init, and the dry-run needs 512 placeholder host
 devices to build the 16×16 (single-pod) and 2×16×16 (multi-pod) meshes.
+They are CPU devices on every host, so the per-cell children it spawns
+never reach for an accelerator.
 
 Usage:
   python -m repro.launch.dryrun --arch mixtral-8x7b --shape train_4k

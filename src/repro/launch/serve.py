@@ -9,9 +9,10 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from typing import List, Optional
 
 
-def main() -> int:
+def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--batch", type=int, default=4)
@@ -33,15 +34,17 @@ def main() -> int:
     ap.add_argument("--health-port", type=int, default=None,
                     help="serve /healthz /readyz /metrics for this replica "
                     "(0 = ephemeral); readiness follows weight swaps")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     import jax
     import jax.numpy as jnp
     from repro.configs import get_arch
     from repro.core.context import CheckpointConfig, CheckpointContext
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.zoo import build_model
-    from repro.serve.engine import ServingEngine, WeightsHandle
+    from repro.serve.engine import ServingEngine
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if not args.full:
         cfg = cfg.reduced()
@@ -107,8 +110,9 @@ def main() -> int:
             return 39
     ckpt.wait()
     toks = jnp.concatenate(out, axis=1) if out else jnp.zeros((args.batch, 0))
+    # the last tokens are the ones a resumed and an uninterrupted run share
     print(f"[serve] generated {toks.shape[1]} tokens/req in "
-          f"{time.time() - t0:.1f}s; sample: {toks[0][:16].tolist()}")
+          f"{time.time() - t0:.1f}s; last 16: {toks[0][-16:].tolist()}")
     ckpt.shutdown()
     return 0
 

@@ -16,28 +16,32 @@ import argparse
 import os
 import subprocess
 import sys
-import time
+from typing import Any, Dict, List, Optional
 
 
-def worker(args) -> int:
+def worker(args, cfg=None, levels=None) -> Dict[str, Any]:
+    """Train (or resume) to ``args.steps``; returns the loop summary, the
+    final state included.  ``cfg`` replaces the ``--arch``/``--full``
+    choice and ``levels`` the default level cycle, for callers that run
+    the worker in-process (``chip_smoke.py``)."""
     import jax
     from repro.configs import get_arch
     from repro.core.context import CheckpointConfig, CheckpointContext
     from repro.data.synthetic import init_data_state
     from repro.ft.failures import FaultInjector, should_inject_from_env
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.zoo import build_model
     from repro.train.loop import LevelSchedule, LoopConfig, run_training
     from repro.train.optimizer import AdamWConfig
     from repro.train.state import init_train_state
     from repro.train.step import make_train_step
 
-    cfg = get_arch(args.arch)
-    if not args.full:
-        cfg = cfg.reduced()
+    enable_compile_cache()
+    if cfg is None:
+        cfg = get_arch(args.arch)
+        if not args.full:
+            cfg = cfg.reduced()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    state = init_train_state(params, jax.random.PRNGKey(args.seed + 1),
-                             init_data_state(args.seed))
     step_fn = make_train_step(
         model, AdamWConfig(total_steps=args.steps, warmup_steps=args.steps // 10),
         remat=not args.no_remat, num_microbatches=args.microbatches)
@@ -67,22 +71,29 @@ def worker(args) -> int:
         total_steps=args.steps,
         ckpt_every=args.ckpt_every,
         kind="DIFF" if args.differential else "FULL",
-        levels=LevelSchedule(),
+        levels=levels if levels is not None else LevelSchedule(),
         heartbeat_path=os.path.join(args.ckpt_dir, "heartbeat"),
         cadence=cadence,
         gap_failure_s=args.heartbeat_timeout,
     )
     try:
-        summary = run_training(model, step_fn, state, ckpt, loop,
-                               args.batch, args.seq, injector=injector)
+        # the initial state is built in the call, so only the loop holds
+        # it: a restore then replaces it on the device instead of sitting
+        # beside it
+        summary = run_training(
+            model, step_fn,
+            init_train_state(model.init(jax.random.PRNGKey(args.seed)),
+                             jax.random.PRNGKey(args.seed + 1),
+                             init_data_state(args.seed)),
+            ckpt, loop, args.batch, args.seq, injector=injector)
     finally:
         ckpt.shutdown()
     brief = {k: v for k, v in summary.items() if k != "state"}
     print(f"[train] done: {brief}")
-    return 0
+    return summary
 
 
-def supervise(args) -> int:
+def supervise(args, argv: Optional[List[str]] = None) -> int:
     """Restart launcher: run worker until success, restarting on failure.
 
     Thin wrapper over :class:`repro.ft.supervisor.Supervisor` — the
@@ -94,8 +105,9 @@ def supervise(args) -> int:
     from repro.chaos import inject
     from repro.ft.supervisor import Supervisor, SupervisorConfig
 
+    argv = sys.argv[1:] if argv is None else argv
     cmd = [sys.executable, "-m", "repro.launch.train"] + [
-        a for a in sys.argv[1:] if a not in ("--supervise",)]
+        a for a in argv if a not in ("--supervise",)]
     env = dict(os.environ)
     if args.inject_at:
         env["OPENCHK_INJECT_AT"] = str(args.inject_at)
@@ -119,7 +131,7 @@ def supervise(args) -> int:
     return sup.run()
 
 
-def main() -> int:
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--steps", type=int, default=100)
@@ -163,7 +175,11 @@ def main() -> int:
     ap.add_argument("--health-port", type=int, default=None,
                     help="with --supervise: serve /healthz /readyz "
                          "/metrics on this port (0 = ephemeral)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    args = parse_args(argv)
     os.makedirs(args.ckpt_dir, exist_ok=True)
     if args.trace_dir:
         # env, not a direct enable: the worker subprocesses a supervisor
@@ -171,8 +187,9 @@ def main() -> int:
         os.makedirs(args.trace_dir, exist_ok=True)
         os.environ["OPENCHK_TRACE_DIR"] = args.trace_dir
     if args.supervise:
-        return supervise(args)
-    return worker(args)
+        return supervise(args, argv)
+    worker(args)
+    return 0
 
 
 if __name__ == "__main__":

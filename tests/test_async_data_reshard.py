@@ -5,10 +5,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container without hypothesis: tiny shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_arch
 from repro.core.async_engine import CPDedicatedThread

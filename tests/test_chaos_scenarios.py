@@ -61,3 +61,24 @@ def test_runner_cli_writes_report(tmp_path, capsys):
     report = json.loads(out.read_text())
     assert report["ok"] and report["total"] == 1
     assert "PASS" in capsys.readouterr().out
+
+
+def test_matrix_runs_supervised_scenarios_first(tmp_path, monkeypatch):
+    """A supervised scenario spawns workers that need the accelerator, so
+    it runs before any in-process scenario initializes a backend here;
+    the report keeps the requested order."""
+    from repro.chaos import scenarios
+    ran = []
+
+    def fake(name, backend, workdir, trace_dir=None):
+        ran.append(name)
+        return scenarios.ScenarioResult(name, backend, True, faults_fired=1,
+                                        recovery_path="local",
+                                        recovery_s=0.0, data_loss_bytes=0)
+
+    monkeypatch.setattr(scenarios, "run_scenario", fake)
+    report = run_matrix(str(tmp_path), backends=("fti",),
+                        names=["corrupt-chunk"], include_supervised=True)
+    assert ran == ["supervised-kill", "corrupt-chunk"]
+    assert [c["name"] for c in report["scenarios"]] == [
+        "corrupt-chunk", "supervised-kill"]

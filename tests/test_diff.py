@@ -1,10 +1,7 @@
 """Differential checkpointing: dirty detection, replay, break-even promote."""
 import numpy as np
 import jax.numpy as jnp
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container without hypothesis: tiny shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.diff import (
     DiffEngine,

@@ -10,6 +10,7 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys
     sys.path.insert(0, "src")
+    from repro.dist.context import make_mesh
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -33,7 +34,7 @@ SCRIPT = textwrap.dedent("""
                                            jnp.int32(16))
 
     # now the same step with the KV cache sequence-sharded over 8 devices
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     def shard_cache(leaf):
         # (L, B, C, KV, dh): shard the cache-seq dim (64 % 8 == 0)
         dims = [None] * leaf.ndim

@@ -3,10 +3,7 @@ import itertools
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container without hypothesis: tiny shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.redundancy import erasure
 from repro.redundancy.groups import Topology

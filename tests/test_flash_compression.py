@@ -2,10 +2,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container without hypothesis: tiny shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.dist.compression import (
     compress_roundtrip_error,

@@ -4,10 +4,7 @@ import os
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container without hypothesis: tiny shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.formats import (
     CHK5CorruptionError,

@@ -19,6 +19,7 @@ SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     import numpy as np
     sys.path.insert(0, "src")
+    from repro.dist.context import make_mesh
     from repro.configs import get_arch
     from repro.core.context import CHK_DIFF, CheckpointConfig, CheckpointContext
     from repro.core.protect import flatten_named
@@ -34,7 +35,7 @@ SCRIPT = textwrap.dedent("""
     params = m.init(jax.random.PRNGKey(0))
 
     # store under a 4x4 mesh, params sharded per the TP/DP rules
-    mesh_a = jax.make_mesh((4, 4), ("data", "model"))
+    mesh_a = make_mesh((4, 4), ("data", "model"))
     params_a = reshard_tree(params, param_shardings(mesh_a, m.param_struct()))
     ctx = CheckpointContext(CheckpointConfig(
         dir=ckpt_dir, backend=backend, dedicated_thread=False,
@@ -64,7 +65,7 @@ SCRIPT = textwrap.dedent("""
     # restart on two other mesh shapes: the restart template carries the
     # new mesh's shardings; load must land every leaf on them, bit-exact
     for shape in ((2, 8), (16, 1)):
-        mesh_b = jax.make_mesh(shape, ("data", "model"))
+        mesh_b = make_mesh(shape, ("data", "model"))
         sh_b = param_shardings(mesh_b, m.param_struct())
         template = reshard_tree(jax.tree.map(jnp.zeros_like, params), sh_b)
         ctx2 = CheckpointContext(CheckpointConfig(

@@ -15,6 +15,7 @@ SUBPROC_COMMON = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     import sys
     sys.path.insert(0, "src")
+    from repro.dist.context import make_mesh
     import glob, shutil
     import jax
     import jax.numpy as jnp
@@ -63,7 +64,7 @@ SUBPROC_COMMON = textwrap.dedent("""
 
 STORE_WIPE_SCRIPT = SUBPROC_COMMON + textwrap.dedent("""
     ckpt_dir = sys.argv[1]
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     state = make_state(mesh)
     ctx = make_ctx(ckpt_dir)
     ctx.store(state, id=1, level=4)
@@ -131,7 +132,7 @@ RESTORE_SCRIPT = SUBPROC_COMMON + textwrap.dedent("""
     probe.shutdown()
 
     # restore onto a DIFFERENT mesh (2x8) through ElasticLoader regions
-    mesh_b = jax.make_mesh((2, 8), ("data", "model"))
+    mesh_b = make_mesh((2, 8), ("data", "model"))
     template = jax.tree.map(jnp.zeros_like, make_state(mesh_b))
     ctx = make_ctx(ckpt_dir)
     restored = ctx.load(template)

@@ -16,6 +16,7 @@ SUBPROC_COMMON = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     import sys
     sys.path.insert(0, "src")
+    from repro.dist.context import make_mesh
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -51,7 +52,7 @@ SUBPROC_COMMON = textwrap.dedent("""
 
 TRAIN_SCRIPT = SUBPROC_COMMON + textwrap.dedent("""
     ckpt_dir = sys.argv[1]
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     ctx = make_ctx(ckpt_dir)
     ctx.store(make_state(mesh), id=1, level=4)
     ctx.store(make_state(mesh, tuned=True), id=2, level=4)
@@ -81,7 +82,7 @@ SERVE_SCRIPT = SUBPROC_COMMON + textwrap.dedent("""
 
     # the serving mesh is a *different* factorization of different size
     # (8 of the 16 devices) — deploy must land the 4x4-trained shards on it
-    mesh_b = jax.make_mesh((1, 8), ("data", "model"))
+    mesh_b = make_mesh((1, 8), ("data", "model"))
     sh = NamedSharding(mesh_b, P("data", "model"))
     template = {"w": jax.device_put(jnp.zeros((64, 64), jnp.float32), sh),
                 "c": jax.device_put(jnp.zeros((256, 256), jnp.float32), sh)}

@@ -295,6 +295,7 @@ SUBPROC_COMMON = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     import sys
     sys.path.insert(0, "src")
+    from repro.dist.context import make_mesh
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -314,7 +315,7 @@ SUBPROC_COMMON = textwrap.dedent("""
 
 STORE_CRASH_SCRIPT = SUBPROC_COMMON + textwrap.dedent("""
     ckpt_dir = sys.argv[1]
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     state = make_state(mesh)
 
     # --- the no-gather Plan guarantee -------------------------------- #
@@ -394,7 +395,7 @@ RESTORE_SCRIPT = SUBPROC_COMMON + textwrap.dedent("""
                                            f"rank0.shard{j}.chk5"))
 
     # restore on a different mesh shape — falls back to id 1
-    mesh_b = jax.make_mesh((2, 8), ("data", "model"))
+    mesh_b = make_mesh((2, 8), ("data", "model"))
     template = make_state(mesh_b)
     template = jax.tree.map(jnp.zeros_like, template)
     ctx = CheckpointContext(CheckpointConfig(

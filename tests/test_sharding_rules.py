@@ -9,6 +9,7 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     import sys
     sys.path.insert(0, "src")
+    from repro.dist.context import make_mesh
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -19,7 +20,7 @@ SCRIPT = textwrap.dedent("""
     from jax.tree_util import tree_flatten_with_path
     from repro.dist.sharding import _path_str
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
 
     # 1) divisibility-aware resolve_spec
     assert resolve_spec(mesh, ("model",), (16,)) == P("model")
@@ -53,7 +54,7 @@ SCRIPT = textwrap.dedent("""
     # 4) batch sharding folds pod into data on multi-pod meshes
     bs = batch_sharding(mesh, 2)
     assert bs.spec == P("data", None)
-    mesh3 = jax.make_mesh((2, 2, 4), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 4), ("pod", "data", "model"))
     bs3 = batch_sharding(mesh3, 2)
     assert bs3.spec == P(("pod", "data"), None)
 

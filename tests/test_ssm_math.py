@@ -1,10 +1,7 @@
 """Chunked SSM algebra vs sequential recurrences (hypothesis sweeps)."""
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container without hypothesis: tiny shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.ssm import _ssd_chunked, _wkv6_chunked
 

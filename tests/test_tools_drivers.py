@@ -94,3 +94,30 @@ def test_heat2d_variants_restart_parity(tmp_path, variant):
     out = mod.run(n=32, steps=40, ckpt_every=10, ckpt_dir=d)
     assert out["restarted"]
     assert abs(out["checksum"] - want) < 1e-3
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir_is_env_or_fixed_in_checkout(env_dir, tmp_path,
+                                                       monkeypatch):
+    """The entry points' compilation cache: ``$JAX_COMPILATION_CACHE_DIR``
+    when set (left to jax), else one fixed directory inside the checkout —
+    never a temp name, so a later run finds what an earlier one cached."""
+    import jax
+    from repro.launch import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path / env_dir))
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
