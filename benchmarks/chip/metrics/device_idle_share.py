@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device, in %
+(1 - busy / window, from the profiler trace)."""
+
+
+def read(obs):
+    t = obs.get("trace") or {}
+    if not t.get("window_s") or not t.get("devices"):
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
