@@ -25,6 +25,8 @@ import weakref
 from dataclasses import dataclass
 from typing import Any, Callable, List, Optional
 
+from repro.telemetry import trace as ttrace
+
 _LIVE: "weakref.WeakSet[CPDedicatedThread]" = weakref.WeakSet()
 
 
@@ -89,9 +91,11 @@ class CPDedicatedThread:
     def submit(self, ckpt_id: int, fn: Callable[[], Any]) -> AsyncResult:
         if not self._alive:
             raise RuntimeError("CP thread already shut down")
-        # fence: keep at most max_inflight pending
-        while self.inflight() >= self._max_inflight:
-            self._wait_one()
+        # fence: keep at most max_inflight pending (the span is recorded
+        # on every submit, so a store that did not wait reads zero)
+        with ttrace.span("cp.wait", ckpt_id=ckpt_id):
+            while self.inflight() >= self._max_inflight:
+                self._wait_one()
         res = AsyncResult(ckpt_id, threading.Event())
         with self._lock:
             self._results.append(res)

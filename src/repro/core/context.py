@@ -46,6 +46,7 @@ from repro.core.pipeline import LoadRequest, StoreRequest
 from repro.core.protect import Protect, normalize_protects
 from repro.core.storage import CHK_DIFF, CHK_FULL, StorageConfig, StoreReport
 from repro.core.tcl import TCL
+from repro.telemetry import trace as ttrace
 
 __all__ = ["CheckpointContext", "CheckpointConfig", "CHK_FULL", "CHK_DIFF",
            "Protect"]
@@ -168,9 +169,11 @@ class CheckpointContext:
         self._check_open()
         if not if_:
             return None
-        self.last_report = self.tcl.store(StoreRequest(
-            tree=state, ckpt_id=int(id), level=int(level), kind=kind,
-            protects=self._protects))
+        with ttrace.span("chk.store", ckpt_id=int(id), level=int(level),
+                         kind=kind):
+            self.last_report = self.tcl.store(StoreRequest(
+                tree=state, ckpt_id=int(id), level=int(level), kind=kind,
+                protects=self._protects))
         return self.last_report
 
     def store_begin(self, *, id: int, level: int,

@@ -37,7 +37,9 @@ import numpy as np
 
 from repro.core.formats import dtype_to_str as dtype_str
 from repro.core.formats import str_to_dtype as str_dtype
+from repro.core.protect import leaf_bytes
 from repro.kernels import ops
+from repro.telemetry import trace as ttrace
 
 
 @dataclass
@@ -64,6 +66,14 @@ class DiffStats:
         return self.dirty_blocks / max(1, self.total_blocks)
 
 
+def _pad_count(n_dirty: int) -> int:
+    """The dirty count ``pack_dirty`` is compiled for: the next power of two."""
+    n_pad = 1
+    while n_pad < n_dirty:
+        n_pad *= 2
+    return n_pad
+
+
 def _pack_dirty_blocks(leaf: Any, dirty: np.ndarray,
                        block_bytes: int) -> np.ndarray:
     """Compact the dirty blocks on device via the diffpack kernel.
@@ -72,9 +82,7 @@ def _pack_dirty_blocks(leaf: Any, dirty: np.ndarray,
     padded to the next power of two (bounded number of compiled variants)
     and the result sliced host-side."""
     n_dirty = int(dirty.shape[0])
-    n_pad = 1
-    while n_pad < n_dirty:
-        n_pad *= 2
+    n_pad = _pad_count(n_dirty)
     idx = np.zeros(n_pad, np.int32)
     idx[:n_dirty] = dirty
     packed = ops.pack_dirty(leaf, jnp.asarray(idx), n_pad, block_bytes)
@@ -146,22 +154,29 @@ class DiffEngine:
                 ops.blockhash(leaf, self.block_bytes))
             self._remember(path, leaf)
 
-    def compute_deltas(self, named: Dict[str, Any]
+    def compute_deltas(self, named: Dict[str, Any],
+                       ckpt_id: Optional[int] = None
                        ) -> Tuple[Optional[List[LeafDelta]], DiffStats]:
-        """→ (deltas, stats); deltas=None means "promote to FULL"."""
+        """→ (deltas, stats); deltas=None means "promote to FULL".
+        ``ckpt_id`` labels the ``diff.hash``/``diff.pack`` spans."""
         stats = DiffStats()
         pending: List[Tuple[str, Any, np.ndarray, np.ndarray]] = []
-        for path, leaf in named.items():
-            if self._is_clean(path, leaf):
-                h_new = self._digests[path]
-                dirty = np.zeros(0, np.int32)
-                stats.skipped_leaves += 1
-            else:
-                h_new = np.asarray(ops.blockhash(leaf, self.block_bytes))
-                dirty = ops.dirty_indices(h_new, self._digests.get(path))
-            stats.total_blocks += h_new.shape[0]
-            stats.dirty_blocks += int(dirty.shape[0])
-            pending.append((path, leaf, h_new, dirty))
+        clean = {p for p, leaf in named.items() if self._is_clean(p, leaf)}
+        stats.skipped_leaves = len(clean)
+        with ttrace.span("diff.hash", ckpt_id=ckpt_id,
+                         leaves=len(named) - len(clean), skipped=len(clean),
+                         bytes=leaf_bytes(leaf for p, leaf in named.items()
+                                          if p not in clean)):
+            for path, leaf in named.items():
+                if path in clean:
+                    h_new = self._digests[path]
+                    dirty = np.zeros(0, np.int32)
+                else:
+                    h_new = np.asarray(ops.blockhash(leaf, self.block_bytes))
+                    dirty = ops.dirty_indices(h_new, self._digests.get(path))
+                stats.total_blocks += h_new.shape[0]
+                stats.dirty_blocks += int(dirty.shape[0])
+                pending.append((path, leaf, h_new, dirty))
 
         if stats.dirty_ratio > self.promote_threshold:
             stats.promoted_full = True
@@ -173,21 +188,27 @@ class DiffEngine:
             return None, stats
 
         deltas = []
-        for path, leaf, h_new, dirty in pending:
-            if dirty.shape[0] == 0:
-                payload = np.zeros((0, self.block_bytes // 4), np.uint32)
-            else:
-                payload = _pack_dirty_blocks(leaf, dirty, self.block_bytes)
-            stats.bytes_written += payload.nbytes
-            deltas.append(LeafDelta(
-                path=path,
-                dtype=dtype_str(leaf.dtype),
-                shape=list(leaf.shape),
-                n_blocks=int(h_new.shape[0]),
-                dirty_idx=dirty,
-                payload=payload,
-                digests=h_new,
-            ))
+        counts = [int(d.shape[0]) for _p, _l, _h, d in pending if d.shape[0]]
+        with ttrace.span("diff.pack", ckpt_id=ckpt_id, leaves=len(counts),
+                         dirty_blocks=stats.dirty_blocks,
+                         bytes=stats.dirty_blocks * self.block_bytes,
+                         n_pad="|".join(str(n) for n in
+                                        sorted({_pad_count(c) for c in counts}))):
+            for path, leaf, h_new, dirty in pending:
+                if dirty.shape[0] == 0:
+                    payload = np.zeros((0, self.block_bytes // 4), np.uint32)
+                else:
+                    payload = _pack_dirty_blocks(leaf, dirty, self.block_bytes)
+                stats.bytes_written += payload.nbytes
+                deltas.append(LeafDelta(
+                    path=path,
+                    dtype=dtype_str(leaf.dtype),
+                    shape=list(leaf.shape),
+                    n_blocks=int(h_new.shape[0]),
+                    dirty_idx=dirty,
+                    payload=payload,
+                    digests=h_new,
+                ))
         for d in deltas:
             self._digests[d.path] = d.digests
         for path, leaf, _h, _d in pending:

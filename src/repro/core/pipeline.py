@@ -54,7 +54,7 @@ from repro.core.diff import (
     u32_flat_to_leaf,
 )
 from repro.core.formats import CHK5Reader, CHK5Writer
-from repro.core.protect import CHK_DIFF, CHK_FULL, Protect, to_host
+from repro.core.protect import CHK_DIFF, CHK_FULL, Protect, leaf_bytes, to_host
 from repro.core.resharding import (
     ShardedLeafRef,
     ShardSnapshot,
@@ -194,6 +194,9 @@ class Plan:
     plan_seconds: float = 0.0          # time spent in plan() itself
     digest_epoch: int = -1             # DIFF only: chain epoch at plan time
     pending_digests: Optional["_PendingDigests"] = None   # FULL: deferred
+    #: id of the ``pipeline.plan`` span (None when tracing is disabled): the
+    #: ``cause`` of the tail's ``pipeline.store`` span on the CP thread
+    span_id: Optional[int] = None
 
 
 @dataclass
@@ -275,8 +278,10 @@ class CheckpointPipeline:
         """Span-wrapped Plan (the only stage on the calling thread — its
         span lands on the training thread's track, not the CP thread's)."""
         with ttrace.span("pipeline.plan", ckpt_id=req.ckpt_id,
-                         level=req.level, kind=req.kind):
-            return self._plan_impl(req)
+                         level=req.level, kind=req.kind) as sp:
+            plan = self._plan_impl(req)
+            plan.span_id = sp.id
+            return plan
 
     def _plan_impl(self, req: StoreRequest) -> Plan:
         """Resolve kind/level, run the on-device diff kernels, snapshot to
@@ -312,7 +317,8 @@ class CheckpointPipeline:
             # fence: an in-flight FULL may still owe its digest update to
             # the CP thread — wait for it so this delta diffs against the
             # post-FULL digests, never stale ones
-            self._wait_digest_fence()
+            with ttrace.span("plan.fence", ckpt_id=req.ckpt_id):
+                self._wait_digest_fence()
         # epoch read BEFORE delta computation: an invalidate() racing in
         # from a CP-thread failure mid-plan must make finish() refuse this
         # delta, not slip past the guard
@@ -320,7 +326,7 @@ class CheckpointPipeline:
         promoted_paths: List[str] = []
         if diff_paths:
             deltas, stats = self.diff.compute_deltas(
-                {p: req.named[p] for p in diff_paths})
+                {p: req.named[p] for p in diff_paths}, ckpt_id=req.ckpt_id)
             dirty_ratio = stats.dirty_ratio
             if deltas is None:              # above break-even: promote
                 promoted = True
@@ -342,7 +348,11 @@ class CheckpointPipeline:
                 {p: req.named[p] for p in full_paths},
                 enabled=self.cfg.sharded_store)
             sharded = sharded or None
-            named_host = to_host(gather) if gather else {}
+            named_host = {}
+            if gather:
+                with ttrace.span("plan.snapshot", ckpt_id=req.ckpt_id,
+                                 bytes=leaf_bytes(gather.values())):
+                    named_host = to_host(gather)
             # digest bookkeeping is skipped when the backend can never
             # consume it (no checkpoint kinds) and for leaves the promote
             # path just hashed; otherwise it is owed — but *deferred* to
@@ -609,7 +619,8 @@ class CheckpointPipeline:
         describes a checkpoint that never committed — invalidate those
         leaves so a later DIFF can't delta against phantom data."""
         with ttrace.span("pipeline.store", ckpt_id=plan.ckpt_id,
-                         level=plan.level, kind=plan.kind) as sp:
+                         level=plan.level, kind=plan.kind,
+                         cause=plan.span_id) as sp:
             report = self._finish_impl(plan)
             report.span_id = sp.id
             return report
@@ -624,7 +635,8 @@ class CheckpointPipeline:
                 # DIFF plan need not wait for this store's I/O, and the
                 # epoch guard below refuses its delta if this tail fails
                 # after the release (invalidate bumps the epoch)
-                self.diff.update_digests_full(plan.pending_digests.named)
+                with ttrace.span("pipeline.digests", ckpt_id=plan.ckpt_id):
+                    self.diff.update_digests_full(plan.pending_digests.named)
                 self._release_digest_fence(plan)
             if plan.kind == CHK_DIFF and plan.digest_epoch != self.diff.epoch:
                 # a store that failed AFTER this one was planned invalidated

@@ -267,6 +267,13 @@ def to_host(named: Dict[str, Any]) -> Dict[str, np.ndarray]:
     return {k: np.asarray(v) for k, v in zip(named.keys(), arrs)}
 
 
+def leaf_bytes(leaves) -> int:
+    """Bytes of ``leaves`` as arrays, read from their shapes (no transfer;
+    a Python scalar counts as its NumPy form)."""
+    return sum(int(v.nbytes) if hasattr(v, "nbytes") else np.asarray(v).nbytes
+               for v in leaves)
+
+
 def leaf_meta(named: Dict[str, Any]) -> Dict[str, Dict]:
     out = {}
     for k, v in named.items():

@@ -510,8 +510,6 @@ class ChunkStream:
                           data=data, name=self.name,
                           seq=len(self._chunks)).data
         digest = hashlib.sha256(data).hexdigest()
-        ttrace.instant("chunk.emit", stream=self.name,
-                       seq=len(self._chunks), bytes=len(data))
         self._chunks.append((digest, self._offset, len(data)))
         self._offset += len(data)
         if up._chunk_known(digest, len(data)):

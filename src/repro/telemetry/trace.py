@@ -36,6 +36,14 @@ end.  :func:`flush` is idempotent and atomic (tmp + replace).
 
 Timestamps are wall-clock microseconds (``time.time_ns``), the one
 timebase that lines up across processes when files are merged.
+
+One clock with the device: while recording, every span also opens a
+``jax.profiler.TraceAnnotation`` of the same name and arguments (its
+``span_id`` among them) on the same thread, closed when the span ends.
+Under ``jax.profiler.start_trace`` the program's spans therefore sit in
+the profiler's trace on the device ops' timeline; while no profile is
+being collected the annotation records nothing.  JAX is imported lazily,
+on the first recorded span, so importing this module stays stdlib-only.
 """
 from __future__ import annotations
 
@@ -77,9 +85,6 @@ class _NullSpan:
     def __exit__(self, *exc) -> None:
         return None
 
-    def event(self, name: str, **args: Any) -> None:
-        return None
-
 
 NULL_SPAN = _NullSpan()
 
@@ -91,14 +96,16 @@ class Span:
     Chrome trace-event contract — cross-thread stages (Plan on the caller,
     the tail on the CP thread) are separate spans correlated by args."""
 
-    __slots__ = ("tracer", "name", "id", "_tid", "_done")
+    __slots__ = ("tracer", "name", "id", "_tid", "_done", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, span_id: int, tid: int):
+    def __init__(self, tracer: "Tracer", name: str, span_id: int, tid: int,
+                 annotation: Any):
         self.tracer = tracer
         self.name = name
         self.id = span_id
         self._tid = tid
         self._done = False
+        self._annotation = annotation
 
     def __enter__(self) -> "Span":
         return self
@@ -112,10 +119,15 @@ class Span:
         self._done = True
         self.tracer._record({"ph": "E", "ts": _now_us(),
                              "pid": os.getpid(), "tid": self._tid})
+        self._annotation.__exit__(None, None, None)
 
-    def event(self, name: str, **args: Any) -> None:
-        """An instant inside this span's track."""
-        self.tracer.instant(name, **args)
+
+def _open_annotation(name: str, args: Dict[str, Any]) -> Any:
+    """The span's ``jax.profiler.TraceAnnotation``, entered on this thread."""
+    from jax.profiler import TraceAnnotation
+    annotation = TraceAnnotation(name, **args)
+    annotation.__enter__()
+    return annotation
 
 
 class Tracer:
@@ -216,7 +228,7 @@ class Tracer:
         else:
             ev["args"] = {"span_id": sid}
         self._record(ev)
-        return Span(self, name, sid, tid)
+        return Span(self, name, sid, tid, _open_annotation(name, ev["args"]))
 
     def instant(self, name: str, cat: str = "openchk", scope: str = "t",
                 **args: Any) -> None:

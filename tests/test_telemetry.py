@@ -90,18 +90,86 @@ def test_span_export_roundtrip_balanced_monotonic_thread_tracks(tmp_path):
     assert marker["ph"] == "i" and marker["args"]["step"] == 3
 
 
-def test_disabled_path_is_a_shared_noop():
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each
+    annotation's name, arguments and the threads it opened and closed on."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **args):
+        log = self.log
+
+        class Annotation:
+            def __enter__(self):
+                log.append(("enter", name, args, threading.get_ident()))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("exit", name, args, threading.get_ident()))
+        return Annotation()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax.profiler
+    stub = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", stub)
+    return stub.log
+
+
+def test_disabled_path_is_a_shared_noop(annotations):
     sp = ttrace.span("ignored", big_arg="x" * 1000)
     assert sp is ttrace.NULL_SPAN and sp.id is None
     with sp:
-        sp.event("also-ignored")
+        ttrace.instant("also-ignored")
     ttrace.instant("ignored-too", step=1)
     assert ttrace.tracer().events() == []
+    assert annotations == []                    # no profiler annotation either
     # and the same calls record once enabled
     ttrace.enable()
     with ttrace.span("real"):
         pass
     assert any(e.get("name") == "real" for e in ttrace.tracer().events())
+    assert [(kind, name) for kind, name, _a, _t in annotations] == [
+        ("enter", "real"), ("exit", "real")]
+
+
+def test_span_opens_a_profiler_annotation_on_its_own_thread(annotations):
+    ttrace.enable()
+    with ttrace.span("outer", ckpt_id=3) as outer:
+        with ttrace.span("inner", leaves=2, cause=None):
+            pass
+
+    def worker():
+        with ttrace.span("cp-side", ckpt_id=3):
+            pass
+    t = threading.Thread(target=worker)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+
+    assert [(kind, name) for kind, name, _a, _t in annotations] == [
+        ("enter", "outer"), ("enter", "inner"), ("exit", "inner"),
+        ("exit", "outer"), ("enter", "cp-side"), ("exit", "cp-side")]
+    args = {name: a for kind, name, a, _t in annotations if kind == "enter"}
+    assert args["outer"] == {"ckpt_id": 3, "span_id": outer.id}
+    assert args["inner"]["leaves"] == 2 and args["inner"]["cause"] is None
+    threads = {}
+    for _kind, name, _a, tid in annotations:
+        threads.setdefault(name, set()).add(tid)
+    assert threads["outer"] == threads["inner"] == {threading.get_ident()}
+    assert threads["cp-side"] == {t.ident}
+
+
+def test_trace_module_imports_no_jax():
+    import subprocess
+    import sys
+    code = ("import sys; import repro.telemetry.trace as t; "
+            "assert 'jax' not in sys.modules; "
+            "t.span('off'); assert 'jax' not in sys.modules")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env, timeout=120)
 
 
 def test_env_dir_protocol_and_merge(tmp_path, monkeypatch):
@@ -168,6 +236,86 @@ def test_metrics_registry_snapshot_and_prometheus():
 # ------------------------------------------------------------------ #
 # pipeline: traced store span tree + metrics parity
 # ------------------------------------------------------------------ #
+
+
+def _profiler_spans(trace_dir):
+    """Complete events of a ``jax.profiler`` perfetto trace: (name, tid,
+    start, end, args); arguments appended to a name (``n#k=v#``) read as
+    args too."""
+    import glob
+    import gzip
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "perfetto_trace.json.gz")))[-1]
+    with gzip.open(path, "rt") as f:
+        data = json.load(f)
+    out = []
+    for e in data["traceEvents"] if isinstance(data, dict) else data:
+        if e.get("ph") != "X":
+            continue
+        name, _, rest = e["name"].partition("#")
+        args = dict(kv.split("=", 1) for kv in rest.rstrip("#").split(",") if "=" in kv)
+        args.update(e.get("args") or {})
+        out.append((name, e["tid"], e["ts"], e["ts"] + e["dur"], args))
+    return out
+
+
+def test_async_diff_store_spans_share_the_profiler_clock(tmp_path):
+    """A DIFF store handed to the CP thread, under the JAX profiler: the
+    directive, Plan and its hashing and packing nest on the caller's track,
+    and the tail's span sits on another track, caused by the Plan span."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.context import CheckpointConfig, CheckpointContext
+
+    ttrace.enable()
+    ctx = CheckpointContext(CheckpointConfig(
+        dir=str(tmp_path / "ckpt"), backend="fti", block_bytes=1024))
+    w = jnp.asarray(np.arange(1 << 14, dtype=np.float32))
+    v = jnp.zeros(1 << 14, jnp.float32)
+    state = {"w": w, "v": v, "b": jnp.ones(1 << 12, jnp.float32)}
+    ctx.store(state, id=1, level=1, kind="DIFF")   # no base yet: promoted
+    ctx.wait()
+    # one block of w dirty, three of v (1 KiB blocks of 256 floats)
+    state = dict(state, w=w.at[:8].set(-1.0), v=v.at[:768:256].set(1.0))
+    trace_dir = str(tmp_path / "profile")
+    jax.profiler.start_trace(trace_dir, create_perfetto_trace=True)
+    try:
+        ctx.store(state, id=2, level=1, kind="DIFF")
+        ctx.wait()
+    finally:
+        jax.profiler.stop_trace()
+        ctx.shutdown()
+
+    spans = [s for s in _profiler_spans(trace_dir) if "span_id" in s[4]]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s[0], []).append(s)
+    (store,) = by_name["chk.store"]
+    assert store[4]["ckpt_id"] == "2"
+    caller = store[1]
+    for name in ("pipeline.plan", "diff.hash", "diff.pack", "cp.wait"):
+        (s,) = by_name[name]
+        assert s[1] == caller and store[2] <= s[2] <= s[3] <= store[3], name
+        assert s[4]["ckpt_id"] == "2", name
+    (plan,) = by_name["pipeline.plan"]
+    for name in ("diff.hash", "diff.pack"):
+        (s,) = by_name[name]
+        assert plan[2] <= s[2] <= s[3] <= plan[3], name
+    (hashed,) = by_name["diff.hash"]
+    assert (hashed[4]["leaves"], hashed[4]["skipped"]) == ("2", "1")
+    assert hashed[4]["bytes"] == str(2 * 4 << 14)
+    (packed,) = by_name["diff.pack"]
+    assert (packed[4]["leaves"], packed[4]["dirty_blocks"]) == ("2", "4")
+    # the padded dirty counts compiled for, whole through the profiler
+    assert (packed[4]["bytes"], packed[4]["n_pad"]) == (str(4 * 1024), "1|4")
+    (tail,) = by_name["pipeline.store"]
+    assert tail[1] != caller
+    assert tail[4]["cause"] == plan[4]["span_id"]
+    # the same spans, on the tracer's own clock
+    events = ttrace.tracer().events()
+    plan_b = [e for e in events if e.get("name") == "pipeline.plan"][-1]
+    tail_b = [e for e in events if e.get("name") == "pipeline.store"][-1]
+    assert tail_b["args"]["cause"] == plan_b["args"]["span_id"]
 
 
 def test_traced_store_span_tree_and_metrics_parity(tmp_path):
