@@ -2,9 +2,12 @@
 
 Per protected leaf, a 64-bit digest per ``block_bytes`` block is kept from
 the previous checkpoint. On a CHK_DIFF store the new digests are computed
-*on device* (Pallas blockhash on TPU; jnp oracle on CPU), the dirty map is
-diffed on host (tiny), dirty blocks are compacted on device by the diffpack
-kernel and only those cross to the host.
+*on device* (Pallas blockhash on TPU; jnp oracle on CPU) for every changed
+leaf and cross to the host in one transfer, the dirty map is diffed on host
+(tiny), dirty blocks are compacted on device by the diffpack kernel and only
+those cross to the host.  Plan waits on the device once, for the digests:
+each packed buffer's copy is started in Plan and completes when Pack first
+reads ``LeafDelta.payload`` — on the CP thread for asynchronous stores.
 
 Digest cache across stores: jax arrays are immutable, so a leaf that is the
 *same object* as at the previous store cannot have changed — its digests
@@ -28,11 +31,10 @@ buffers, then bit-cast back to the leaf dtype/shape.
 from __future__ import annotations
 
 import weakref
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.formats import dtype_to_str as dtype_str
@@ -49,8 +51,23 @@ class LeafDelta:
     shape: List[int]
     n_blocks: int
     dirty_idx: np.ndarray        # (n_dirty,) int32
-    payload: np.ndarray          # (n_dirty, block_elems) uint32
     digests: np.ndarray          # (n_blocks, 2) uint32 — post-store state
+    #: (n_pad, block_elems) uint32 packed blocks, padding rows included:
+    #: on the device, its host copy in flight, until the first payload read
+    blocks: Any = field(repr=False)
+
+    @property
+    def in_flight_bytes(self) -> int:
+        """Bytes still crossing to the host, the ``n_pad`` padding included."""
+        return 0 if isinstance(self.blocks, np.ndarray) else self.blocks.nbytes
+
+    @property
+    def payload(self) -> np.ndarray:
+        """(n_dirty, block_elems) uint32.  The first read waits for the copy
+        and drops the device buffer."""
+        if not isinstance(self.blocks, np.ndarray):
+            self.blocks = np.asarray(self.blocks)
+        return self.blocks[: self.dirty_idx.shape[0]]
 
 
 @dataclass
@@ -75,18 +92,23 @@ def _pad_count(n_dirty: int) -> int:
 
 
 def _pack_dirty_blocks(leaf: Any, dirty: np.ndarray,
-                       block_bytes: int) -> np.ndarray:
-    """Compact the dirty blocks on device via the diffpack kernel.
+                       block_bytes: int) -> jax.Array:
+    """Compact the dirty blocks on device via the diffpack kernel and start
+    the packed buffer's copy to the host without waiting for it.
 
     ``pack_dirty`` jits on a static dirty count, so the index vector is
-    padded to the next power of two (bounded number of compiled variants)
-    and the result sliced host-side."""
+    padded to the next power of two (bounded number of compiled variants);
+    ``LeafDelta.payload`` slices the padding off host-side.  The index
+    vector goes to the jitted call as a host array: the call's own transfer
+    keeps it on the dispatch fast path, where a separately placed device
+    array is re-placed by the slow one."""
     n_dirty = int(dirty.shape[0])
     n_pad = _pad_count(n_dirty)
     idx = np.zeros(n_pad, np.int32)
     idx[:n_dirty] = dirty
-    packed = ops.pack_dirty(leaf, jnp.asarray(idx), n_pad, block_bytes)
-    return np.asarray(packed)[:n_dirty]
+    packed = ops.pack_dirty(leaf, idx, n_pad, block_bytes)
+    packed.copy_to_host_async()
+    return packed
 
 
 class DiffEngine:
@@ -158,21 +180,29 @@ class DiffEngine:
                        ckpt_id: Optional[int] = None
                        ) -> Tuple[Optional[List[LeafDelta]], DiffStats]:
         """→ (deltas, stats); deltas=None means "promote to FULL".
-        ``ckpt_id`` labels the ``diff.hash``/``diff.pack`` spans."""
+        ``ckpt_id`` labels the ``diff.hash``/``diff.pack`` spans.
+
+        One blocking device→host transfer: the digest tables of every
+        changed leaf, hashed first and fetched together.  The deltas'
+        packed blocks are still crossing when this returns (see
+        ``LeafDelta.payload``)."""
         stats = DiffStats()
         pending: List[Tuple[str, Any, np.ndarray, np.ndarray]] = []
         clean = {p for p, leaf in named.items() if self._is_clean(p, leaf)}
+        hashed = [p for p in named if p not in clean]
         stats.skipped_leaves = len(clean)
         with ttrace.span("diff.hash", ckpt_id=ckpt_id,
-                         leaves=len(named) - len(clean), skipped=len(clean),
-                         bytes=leaf_bytes(leaf for p, leaf in named.items()
-                                          if p not in clean)):
+                         leaves=len(hashed), skipped=len(clean),
+                         bytes=leaf_bytes(named[p] for p in hashed),
+                         fetches=int(bool(hashed))):
+            tables = dict(zip(hashed, jax.device_get(
+                [ops.blockhash(named[p], self.block_bytes) for p in hashed])))
             for path, leaf in named.items():
                 if path in clean:
                     h_new = self._digests[path]
                     dirty = np.zeros(0, np.int32)
                 else:
-                    h_new = np.asarray(ops.blockhash(leaf, self.block_bytes))
+                    h_new = tables[path]
                     dirty = ops.dirty_indices(h_new, self._digests.get(path))
                 stats.total_blocks += h_new.shape[0]
                 stats.dirty_blocks += int(dirty.shape[0])
@@ -189,25 +219,25 @@ class DiffEngine:
 
         deltas = []
         counts = [int(d.shape[0]) for _p, _l, _h, d in pending if d.shape[0]]
+        stats.bytes_written = stats.dirty_blocks * self.block_bytes
         with ttrace.span("diff.pack", ckpt_id=ckpt_id, leaves=len(counts),
                          dirty_blocks=stats.dirty_blocks,
-                         bytes=stats.dirty_blocks * self.block_bytes,
+                         bytes=stats.bytes_written, deferred=len(counts),
                          n_pad="|".join(str(n) for n in
                                         sorted({_pad_count(c) for c in counts}))):
             for path, leaf, h_new, dirty in pending:
                 if dirty.shape[0] == 0:
-                    payload = np.zeros((0, self.block_bytes // 4), np.uint32)
+                    blocks = np.zeros((0, self.block_bytes // 4), np.uint32)
                 else:
-                    payload = _pack_dirty_blocks(leaf, dirty, self.block_bytes)
-                stats.bytes_written += payload.nbytes
+                    blocks = _pack_dirty_blocks(leaf, dirty, self.block_bytes)
                 deltas.append(LeafDelta(
                     path=path,
                     dtype=dtype_str(leaf.dtype),
                     shape=list(leaf.shape),
                     n_blocks=int(h_new.shape[0]),
                     dirty_idx=dirty,
-                    payload=payload,
                     digests=h_new,
+                    blocks=blocks,
                 ))
         for d in deltas:
             self._digests[d.path] = d.digests

@@ -6,8 +6,9 @@ incremental, any backend — flows through the same four stages:
     Plan    kind/level resolution, the diff→full promote decision, and the
             only work that must stay on the calling thread: the device→host
             snapshot and (for CHK_DIFF) the on-device blockhash/diffpack
-            kernels.  Runs in submission order, so back-to-back asynchronous
-            DIFF stores see a consistent digest chain.  FULL stores on
+            kernels, whose packed blocks finish crossing in Pack.  Runs
+            in submission order, so back-to-back asynchronous DIFF stores
+            see a consistent digest chain.  FULL stores on
             diff-capable backends owe digest bookkeeping too, but it is
             *deferred* to the tail behind a fence (``_wait_digest_fence``)
             — a DIFF planned after an in-flight FULL waits for that FULL's
@@ -170,9 +171,10 @@ class LoadRequest:
 class Plan:
     """Resolved store decision (output of Plan, input to Pack/Place/Commit).
 
-    After ``plan()`` returns, the checkpoint content is frozen host-side
-    (FULL: host snapshot; DIFF: compacted dirty blocks) — the remaining
-    stages touch no device state and may run on a CP-dedicated thread."""
+    After ``plan()`` returns, the checkpoint content is frozen (FULL: host
+    snapshot, or immutable shards whose copies are in flight; DIFF:
+    compacted dirty blocks, their copies in flight) — the remaining stages
+    launch no device work and may run on a CP-dedicated thread."""
     ckpt_id: int
     level: int
     kind: str
@@ -402,10 +404,12 @@ class CheckpointPipeline:
     def abort_plan(self, plan: Plan) -> None:
         """A planned store will never reach finish() (e.g. the CP submit
         itself raised): release its fence so later DIFF plans don't block
-        forever. No invalidate needed — the digests still describe the
-        last *committed* checkpoint, which is the correct DIFF base when
-        this store never happened."""
+        forever, and drop its deltas' device buffers. No invalidate
+        needed — the digests still describe the last *committed*
+        checkpoint, which is the correct DIFF base when this store never
+        happened."""
         self._release_digest_fence(plan)
+        plan.deltas = None
 
     def plan_external(self, ckpt_id: int, level: int,
                       extra_meta: Optional[Dict[str, Any]] = None) -> Plan:
@@ -468,7 +472,7 @@ class CheckpointPipeline:
             if plan.named_host:
                 pack_named(w, plan.named_host, plan.specs, self.pack_tiers)
             if plan.deltas:
-                self._serialize_deltas(w, plan.deltas, plan.specs)
+                self._serialize_deltas(w, plan)
         nbytes = os.path.getsize(path) + sum(
             os.path.getsize(p) for p in shard_files)
         return Packed(stage_dir=d, path=path, nbytes=nbytes,
@@ -484,11 +488,18 @@ class CheckpointPipeline:
                 return s
         return None
 
-    def _serialize_deltas(self, w: CHK5Writer, deltas: List[LeafDelta],
-                          specs: Optional[Dict[str, Optional[Protect]]]
-                          ) -> None:
-        specs = specs or {}
-        for d in deltas:
+    def _serialize_deltas(self, w: CHK5Writer, plan: Plan) -> None:
+        specs = plan.specs or {}
+        # the packed blocks' host copies were started in Plan: complete
+        # them here, behind the training thread's next steps when this
+        # tail runs on the CP thread
+        in_flight = sum(d.in_flight_bytes for d in plan.deltas)
+        if in_flight:
+            with ttrace.span("delta.fetch", ckpt_id=plan.ckpt_id,
+                             bytes=in_flight):
+                for d in plan.deltas:
+                    d.payload           # the first read waits for the copy
+        for d in plan.deltas:
             g = f"delta/{d.path}"
             w.write_dataset(f"{g}/idx", d.dirty_idx)
             w.write_dataset(f"{g}/blocks", d.payload)
@@ -652,6 +663,7 @@ class CheckpointPipeline:
             return self.commit(plan, packed)
         except BaseException:
             self.diff.invalidate(self._plan_leaf_paths(plan))
+            plan.deltas = None          # no device buffer outlives a failure
             raise
         finally:
             self._release_digest_fence(plan)

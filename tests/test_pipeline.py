@@ -372,3 +372,96 @@ def test_backend_capabilities_and_shared_stacks(tmp_path):
     assert caps["fti"]["diff"] and not caps["scr"]["diff"]
     assert caps["veloc"]["dedicated_thread"]
     assert not caps["scr"]["dedicated_thread"]
+
+
+# ------------------------------------------------------------------ #
+# deferred DIFF payloads: Plan starts the packed copies, Pack reads them
+# ------------------------------------------------------------------ #
+
+
+@pytest.mark.parametrize("dedicated_thread", [True, False])
+def test_diff_store_with_deferred_payload_roundtrips(tmp_path,
+                                                     dedicated_thread):
+    """A DIFF store restores bit-exactly whether Pack reads its payload on
+    the CP thread (copy overlapped) or right after Plan (synchronous)."""
+    cfg = CheckpointConfig(dir=str(tmp_path / "p"), backend="fti",
+                           dedicated_thread=dedicated_thread, block_bytes=256)
+    ctx = CheckpointContext(cfg)
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(4096).astype(np.float32))
+    y = jnp.asarray(rng.randint(0, 255, 3000).astype(np.uint8))
+    ctx.store({"x": x, "y": y}, id=1, level=1)
+    x2 = x.at[5].set(-1.0).at[700].set(2.5).at[3000].set(7.0)
+    y2 = y.at[2999].set(0)
+    ctx.store({"x": x2, "y": y2}, id=2, level=1, kind=CHK_DIFF)
+    ctx.wait()
+    ctx.shutdown()
+
+    ctx2 = CheckpointContext(CheckpointConfig(dir=str(tmp_path / "p"),
+                                              backend="fti"))
+    named, meta = ctx2.tcl.backend.engine.load_latest()
+    assert meta["kind"] == CHK_DIFF and meta["id"] == 2
+    assert named["x"].tobytes() == np.asarray(x2).tobytes()
+    assert named["y"].tobytes() == np.asarray(y2).tobytes()
+    ctx2.shutdown()
+
+
+def _planned_diff(tmp_path):
+    """A synchronous FTI backend with a committed FULL base, and the Plan
+    of a DIFF store over it whose packed copies are still in flight."""
+    from repro.core.pipeline import StoreRequest
+    cfg = StorageConfig(root=str(tmp_path / "shared"), block_bytes=256)
+    cluster = SimulatedCluster(str(tmp_path / "cluster"), 1)
+    b = make_backend(cfg, cluster.comms[0], "fti", dedicated_thread=False)
+    x = jnp.arange(4096, dtype=jnp.float32)
+    b.tcl_store({"x": x}, 1, 1, CHK_FULL)
+    pipe = b.pipeline
+    plan = pipe.plan(StoreRequest(named={"x": x.at[9].set(-1.0)}, ckpt_id=2,
+                                  level=1, kind=CHK_DIFF))
+    assert plan.kind == CHK_DIFF and plan.deltas[0].in_flight_bytes
+    return pipe, plan
+
+
+def test_failed_diff_tail_invalidates_and_drops_device_payload(tmp_path):
+    """A DIFF tail refused by the epoch guard (a store planned before it
+    failed) invalidates its leaves and drops its deltas, so no packed
+    device buffer outlives the failure."""
+    import gc
+    import weakref
+    pipe, plan = _planned_diff(tmp_path)
+    packs = [weakref.ref(d.blocks) for d in plan.deltas]
+    pipe.diff.invalidate(["elsewhere"])           # a failed earlier store
+    with pytest.raises(RuntimeError, match="digest base invalidated"):
+        pipe.finish(plan)
+    assert plan.deltas is None
+    assert "x" not in pipe.diff._digests          # the chain forgot x
+    gc.collect()
+    assert all(r() is None for r in packs)
+
+
+def test_aborted_diff_plan_drops_device_payload(tmp_path):
+    import gc
+    import weakref
+    pipe, plan = _planned_diff(tmp_path)
+    packs = [weakref.ref(d.blocks) for d in plan.deltas]
+    pipe.abort_plan(plan)
+    assert plan.deltas is None
+    gc.collect()
+    assert all(r() is None for r in packs)
+
+
+def test_promoted_diff_never_packs(tmp_path, monkeypatch):
+    """Above the break-even dirty ratio the store promotes to FULL from
+    the digests alone: the diffpack kernel never runs."""
+    import repro.core.diff as diff_mod
+    calls = []
+    monkeypatch.setattr(diff_mod.ops, "pack_dirty",
+                        lambda *a, **k: calls.append(1))
+    cfg = CheckpointConfig(dir=str(tmp_path / "pr"), backend="fti",
+                           dedicated_thread=False, block_bytes=256)
+    ctx = CheckpointContext(cfg)
+    x = jnp.arange(4096, dtype=jnp.float32)
+    ctx.store({"x": x}, id=1, level=1)
+    rep = ctx.store({"x": x + 1.0}, id=2, level=1, kind=CHK_DIFF)
+    assert rep.promoted_full and rep.kind == CHK_FULL and not calls
+    ctx.shutdown()

@@ -308,14 +308,59 @@ def test_async_diff_store_spans_share_the_profiler_clock(tmp_path):
     assert (packed[4]["leaves"], packed[4]["dirty_blocks"]) == ("2", "4")
     # the padded dirty counts compiled for, whole through the profiler
     assert (packed[4]["bytes"], packed[4]["n_pad"]) == (str(4 * 1024), "1|4")
+    assert (hashed[4]["fetches"], packed[4]["deferred"]) == ("1", "2")
     (tail,) = by_name["pipeline.store"]
     assert tail[1] != caller
+    (fetch,) = by_name["delta.fetch"]
+    assert fetch[1] == tail[1] and tail[2] <= fetch[2] <= fetch[3] <= tail[3]
     assert tail[4]["cause"] == plan[4]["span_id"]
     # the same spans, on the tracer's own clock
     events = ttrace.tracer().events()
     plan_b = [e for e in events if e.get("name") == "pipeline.plan"][-1]
     tail_b = [e for e in events if e.get("name") == "pipeline.store"][-1]
     assert tail_b["args"]["cause"] == plan_b["args"]["span_id"]
+
+
+def test_diff_plan_fetches_once_and_pack_completes_the_copies(tmp_path):
+    """DIFF Plan waits on the device once, for the digests of the changed
+    leaves (never when all are clean), and hands every dirty leaf's packed
+    copy to Pack in flight: ``delta.fetch`` completes them on the CP thread,
+    the ``n_pad`` padding counted in its bytes."""
+    import jax.numpy as jnp
+    from repro.core.context import CheckpointConfig, CheckpointContext
+
+    ttrace.enable()
+    ctx = CheckpointContext(CheckpointConfig(
+        dir=str(tmp_path / "ckpt"), backend="fti", block_bytes=1024))
+    w = jnp.asarray(np.arange(1 << 14, dtype=np.float32))
+    v = jnp.zeros(1 << 14, jnp.float32)
+    state = {"w": w, "v": v, "b": jnp.ones(1 << 12, jnp.float32)}
+    ctx.store(state, id=1, level=1, kind="DIFF")   # no base yet: promoted
+    ctx.wait()
+    ctx.store(state, id=2, level=1, kind="DIFF")   # every leaf clean
+    ctx.wait()
+    # one block of w dirty, three of v (1 KiB blocks of 256 floats)
+    state = dict(state, w=w.at[:8].set(-1.0), v=v.at[:768:256].set(1.0))
+    ctx.store(state, id=3, level=1, kind="DIFF")
+    ctx.wait()
+    ctx.shutdown()
+
+    events = [e for e in ttrace.tracer().events() if e["ph"] == "B"]
+    hashed = [e["args"] for e in events if e["name"] == "diff.hash"]
+    assert [(a["ckpt_id"], a["leaves"], a["fetches"]) for a in hashed] == [
+        (1, 3, 1), (2, 0, 0), (3, 2, 1)]
+    packed = [e["args"] for e in events if e["name"] == "diff.pack"]
+    assert [(a["ckpt_id"], a["leaves"], a["deferred"], a["n_pad"])
+            for a in packed] == [(2, 0, 0, ""), (3, 2, 2, "1|4")]
+    (store,) = [e for e in events if e["name"] == "chk.store"
+                and e["args"]["ckpt_id"] == 3]
+    (fetch,) = [e for e in events if e["name"] == "delta.fetch"]
+    assert fetch["tid"] != store["tid"]
+    assert fetch["args"]["ckpt_id"] == 3
+    assert fetch["args"]["bytes"] == (1 + 4) * 1024
+    (tail,) = [e for e in events if e["name"] == "pipeline.store"
+               and e["args"]["ckpt_id"] == 3]
+    assert tail["tid"] == fetch["tid"] and tail["ts"] <= fetch["ts"]
 
 
 def test_traced_store_span_tree_and_metrics_parity(tmp_path):
