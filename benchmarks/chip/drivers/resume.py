@@ -24,6 +24,7 @@ import numpy as np
 
 import compare
 import reference
+import registry
 import trainjob
 
 
@@ -31,6 +32,7 @@ class Job:
     def __init__(self, run) -> None:
         self.run = run
         self.w, self.c = run.workload, run.config
+        self.family = registry.reference(run.config)
         self.seed32 = run.seed & 0xFFFFFFFF
         self.attempted = 0
         self.failed = 0
@@ -41,7 +43,7 @@ class Job:
     def _template(self):
         from repro.data.synthetic import init_data_state
         from repro.train.state import init_train_state
-        params = reference.init_params(self.keys["params"], self.c)
+        params = self.family.init_params(self.keys["params"], self.c)
         return init_train_state(params, jax.numpy.copy(self.keys["rng"]),
                                 init_data_state(self.seed32))
 
@@ -56,10 +58,10 @@ class Job:
         from repro.models.zoo import build_model
 
         enable_compile_cache()
-        self.cfg = trainjob.arch_config(self.c)
+        self.cfg = self.family.arch_config(self.c)
         self.keys = trainjob.keys(self.run.seed)
         state = self._template()
-        trainjob.check_layout(build_model(self.cfg), state.params)
+        trainjob.check_layout(build_model(self.cfg), state.params, self.c)
         ctx = self._context()
         try:
             ctx.store(state, id=1, level=self.w["store"]["level"], kind="FULL")
@@ -72,7 +74,7 @@ class Job:
             "--ckpt-dir", str(self.run.work / "ckpt"), "--steps", "1",
             "--batch", str(w["batch"]), "--seq", str(w["seq"]), "--seed", "0",
             "--ckpt-every", "2", "--backend", w["store"]["backend"]])
-        self.params0 = reference.init_params(self.keys["params"], self.c)
+        self.params0 = self.family.init_params(self.keys["params"], self.c)
         self._resume()
         self.attempted = self.failed = 0
         self.durations = []
@@ -151,7 +153,8 @@ class Job:
             b = self._batch(i)
             return b if rows is None else {k: v[:rows] for k, v in b.items()}
         return trainjob.reference_readings(
-            self.c, self.w["optimizer"], self.keys["params"], batch, 1, "all", dot=dot)
+            self.family, self.c, self.w["optimizer"], self.keys["params"], batch, 1, "all",
+            dot=dot)
 
     def build(self) -> None:
         """For the control: set-up up to the first resume's readings."""

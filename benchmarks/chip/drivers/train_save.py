@@ -6,7 +6,8 @@ steps, asynchronously on the CP thread.  The workload file says what
 trains (``"all"``: the whole model through ``train/step.make_train_step``;
 a list of parts of the stage's last layer, e.g. ``["attn", "ln1"]``: those
 and the final norm, everything else frozen and without optimizer state,
-built from ``compute_loss`` and ``adamw_update``) and how it saves
+built from ``compute_loss`` and ``adamw_update`` on the tree as the
+configuration's reference family cuts it) and how it saves
 (``kind``, ``level``, ``every``, optional ``protect`` selectors).
 
 Set-up makes weights and optimizer state on the device from the seed,
@@ -34,8 +35,8 @@ import numpy as np
 
 import compare
 import digests
-import flops
 import reference
+import registry
 import trainjob
 
 FIRST_STEPS = 3
@@ -46,59 +47,73 @@ def _annotate(name: str):
 
 
 def finetune_forward(params, batch, cfg, remat=False):
-    """The model with the stage's layers before the last run forward only:
-    ``params`` holds ``prefix`` (stacked layers 0..L-2), ``last`` (layer
-    L-1, its trained parts in place), ``ln_f`` and the embedding/head."""
+    """The model with the layers before the last run forward only:
+    ``params`` holds ``rest`` (the model without its last layer, as the
+    reference family's ``split_last`` gives it, the trained final norm in
+    place) and ``last`` (the last layer, its trained parts in place)."""
     import jax.numpy as jnp
     from repro.models.layers import cast_floating
     from repro.models.transformer import lm_backbone, lm_logits
 
     cdt = jnp.dtype(cfg.compute_dtype)
-    h = params["embed"][batch["tokens"]].astype(cdt)
+    rest = params["rest"]
+    h = rest["embed"][batch["tokens"]].astype(cdt)
     if cfg.n_layers > 1:
-        prefix = cast_floating(params["prefix"], cdt)
-        h, _ = lm_backbone({"groups": [prefix]}, h, cfg, remat=remat)
+        h, _ = lm_backbone({"groups": cast_floating(rest["groups"], cdt)}, h, cfg,
+                           remat=remat)
     last = cast_floating(jax.tree.map(lambda x: x[None], params["last"]), cdt)
     h, aux = lm_backbone({"groups": [last]}, h, cfg, remat=remat)
-    head = {k: params[k] for k in ("ln_f", "embed", "lm_head") if k in params}
+    head = {k: rest[k] for k in ("ln_f", "embed", "lm_head") if k in rest}
     return lm_logits(cast_floating(head, cdt), h, cfg), aux
 
 
-def make_finetune_step(model, opt_cfg, parts):
-    """step(state, batch) → (state, loss): AdamW on the last layer's slice of
-    the stacked leaves of ``parts`` and on the final norm; ``state.opt``
-    covers only those.  Other leaves stay the same arrays."""
+def _read_leaves(family, params, parts) -> List[int]:
+    """Indices of the leaves of ``params`` that the family's ``trained_part``
+    takes the trained parts from: each leaf stands in as a broadcast view
+    of its own index."""
+    leaves, treedef = jax.tree.flatten(params)
+    probe = treedef.unflatten([np.broadcast_to(np.int32(i), x.shape)
+                               for i, x in enumerate(leaves)])
+    return sorted({int(x.flat[0]) for x in jax.tree.leaves(family.trained_part(probe, parts))})
+
+
+def make_finetune_step(model, opt_cfg, family, params, parts):
+    """step(state, batch) → (state, loss): AdamW on the parts ``parts`` of the
+    last layer and on the final norm (``family.trained_part``); ``state.opt``
+    covers only those.  The leaves of ``params``' tree that hold no trained
+    part stay the same arrays."""
     from repro.train.optimizer import adamw_update
     from repro.train.step import compute_loss
 
     ft_model = dataclasses.replace(
         model, forward=functools.partial(finetune_forward, cfg=model.cfg))
+    written = _read_leaves(family, params, parts)
 
     def step(state, batch):
-        params = state.params
-        stacked = params["groups"][0]
-        last = jax.tree.map(lambda x: x[-1], stacked)
-        trained = trainjob.trained_part(params, parts)
-        frozen = {k: v for k, v in params.items() if k not in ("groups", "ln_f")}
-        frozen["prefix"] = jax.tree.map(lambda x: x[:-1], stacked)
+        rest, last = family.split_last(state.params)
+        trained = family.trained_part(state.params, parts)
 
         def loss_of(tr):
-            p = dict(frozen, last=dict(last, **tr["last"]), ln_f=tr["ln_f"])
+            p = {"rest": dict(rest, ln_f=tr["ln_f"]), "last": dict(last, **tr["last"])}
             loss, _ = compute_loss(ft_model, p, batch, remat=True)
             return loss
 
         loss, grads = jax.value_and_grad(loss_of)(trained)
         new_tr, new_opt, _ = adamw_update(opt_cfg, grads, state.opt, trained)
-        new_parts = {k: jax.tree.map(lambda x, s: x.at[-1].set(s), stacked[k],
-                                     new_tr["last"][k]) for k in parts}
-        return state.step + 1, new_parts, new_tr["ln_f"], new_opt, loss
+        joined = family.join_last(dict(rest, ln_f=new_tr["ln_f"]),
+                                  dict(last, **new_tr["last"]))
+        if jax.tree.structure(joined) != jax.tree.structure(state.params):
+            raise ValueError("the reference family's join_last does not give back "
+                             "the tree that split_last took")
+        new = jax.tree.leaves(joined)
+        return state.step + 1, {i: new[i] for i in written}, new_opt, loss
 
     jitted = jax.jit(step)
 
     def run(state, batch):
-        count, new_parts, ln_f, opt, loss = jitted(state, batch)
-        groups = [dict(state.params["groups"][0], **new_parts)]
-        params = dict(state.params, groups=groups, ln_f=ln_f)
+        count, new, opt, loss = jitted(state, batch)
+        leaves, treedef = jax.tree.flatten(state.params)
+        params = treedef.unflatten([new.get(i, x) for i, x in enumerate(leaves)])
         return state._replace(step=count, params=params, opt=opt), loss
 
     return run
@@ -108,6 +123,7 @@ class Job:
     def __init__(self, run) -> None:
         self.run = run
         self.w, self.c = run.workload, run.config
+        self.family = registry.reference(run.config)
         self.attempted = 0
         self.failed = 0
         self.block_s: List[float] = []
@@ -167,12 +183,12 @@ class Job:
 
         enable_compile_cache()
         w, c, seed = self.w, self.c, self.run.seed
-        self.cfg = trainjob.arch_config(c)
+        self.cfg = self.family.arch_config(c)
         self.model = build_model(self.cfg)
         self.opt_cfg = trainjob.adamw_config(w["optimizer"])
         self.keys = trainjob.keys(seed)
-        params = reference.init_params(self.keys["params"], c)
-        trainjob.check_layout(self.model, params)
+        params = self.family.init_params(self.keys["params"], c)
+        trainjob.check_layout(self.model, params, c)
         if w["trained"] == "all":
             self.state = init_train_state(params, jax.numpy.copy(self.keys["rng"]),
                                           init_data_state(seed & 0xFFFFFFFF))
@@ -185,10 +201,11 @@ class Job:
         else:
             self.state = TrainState(
                 step=jax.numpy.zeros((), jax.numpy.int32), params=params,
-                opt=adamw_init(trainjob.trained_part(params, w["trained"])),
+                opt=adamw_init(self._trained(params)),
                 rng=jax.numpy.copy(self.keys["rng"]),
                 data_state=init_data_state(seed & 0xFFFFFFFF))
-            self.step = make_finetune_step(self.model, self.opt_cfg, w["trained"])
+            self.step = make_finetune_step(self.model, self.opt_cfg, self.family, params,
+                                           w["trained"])
         self.index = 0
 
     def _batch(self, index: int):
@@ -197,7 +214,7 @@ class Job:
                                    self.c["vocab_size"])
 
     def _trained(self, params):
-        return trainjob.trained_part(params, self.w["trained"])
+        return self.family.trained_part(params, self.w["trained"])
 
     def first_steps(self) -> Dict[str, Any]:
         """Steps 1..3 through the window's own call and feed; the program's
@@ -210,7 +227,7 @@ class Job:
             if i == 0:
                 out["grad"] = trainjob.first_gradient_norms(self.state.opt.mu,
                                                             self.opt_cfg.b1)
-        start = self._trained(reference.init_params(self.keys["params"], self.c))
+        start = self._trained(self.family.init_params(self.keys["params"], self.c))
         now = self._trained(self.state.params)
         out["paths"] = trainjob.leaf_paths(now)
         out["change"] = trainjob.change_norms(now, start)
@@ -290,7 +307,7 @@ class Job:
         return {
             "window_s": self.window_s,
             "steps": self.window_steps,
-            "flops_per_step": flops.step_flops(self.c, self.w),
+            "flops_per_step": self.family.step_flops(self.c, self.w),
             "store_block_s": list(self.block_s),
             "save_tail_s": [r.seconds for r in self.reports],
             "hashed_bytes": self.hashed_bytes,
@@ -355,8 +372,8 @@ class Job:
             b = self._batch(i)
             return b if rows is None else {k: v[:rows] for k, v in b.items()}
         return trainjob.reference_readings(
-            self.c, self.w["optimizer"], self.keys["params"], batch, FIRST_STEPS,
-            self.w["trained"], dot=dot)
+            self.family, self.c, self.w["optimizer"], self.keys["params"], batch,
+            FIRST_STEPS, self.w["trained"], dot=dot)
 
     def close(self) -> None:
         ctx, self.ctx = getattr(self, "ctx", None), None

@@ -1,7 +1,8 @@
 """Share of the chip's bf16 peak that the window's steps needed, in %:
-operations a step requires (``flops.step_flops``: no recomputation, no
-capacity padding, causal attention) times steps over the window's host
-clock, over the peak.  Bounds every kernel roofline of the step."""
+operations a step requires (the reference family's ``step_flops``: no
+recomputation, no capacity padding, causal attention) times steps over
+the window's host clock, over the peak.  Bounds every kernel roofline of
+the step."""
 
 
 def read(obs):

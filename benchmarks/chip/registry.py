@@ -6,8 +6,10 @@ files and a ``BENCHMARK.json`` entry, never by editing one:
 
 - ``BENCHMARK.json`` (repository root): cells, metrics, bounds;
 - ``workloads/<cell>.json``: the cell's traffic, driver and limits;
-- ``configs/<config>.json``: sizes as run (``reference.py`` is their plain
-  reference);
+- ``configs/<config>.json``: sizes as run, and the reference family the
+  configuration names (``"reference": "<family>"``);
+- ``references/<family>.py``: one per architecture, its plain reference,
+  program mapping, tree split and operation count (``FAMILY``);
 - ``drivers/<driver>.py``: one per kind of traffic, with a ``Job`` class;
 - ``metrics/<metric>.py``: a ``read(obs)`` per per-layer metric.
 """
@@ -68,6 +70,28 @@ def _module(path: Path, qualname: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+# what every reference family exposes, and nothing else is asked of one
+FAMILY = ("init_params", "loss", "trained_part", "split_last", "join_last",
+          "arch_config", "step_flops")
+
+
+def reference(config: Dict[str, Any], base: Path = HERE):
+    """The reference family that configuration ``config`` names under its
+    ``reference`` key: ``references/<family>.py``.  A configuration that
+    names none, a family without a file, and a family that lacks one of
+    ``FAMILY`` are errors."""
+    if "reference" not in config:
+        raise ValueError('the configuration names no reference family: give it '
+                         '"reference": "<family>" for references/<family>.py')
+    name = check_name(config["reference"])
+    path = base / "references" / f"{name}.py"
+    family = _module(path, f"reference_{name}")
+    missing = [f for f in FAMILY if not callable(getattr(family, f, None))]
+    if missing:
+        raise AttributeError(f"{path} lacks {', '.join(missing)}")
+    return family
 
 
 def driver(name: str, base: Path = HERE):

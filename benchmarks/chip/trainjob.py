@@ -1,6 +1,8 @@
-"""What both training drivers share: the configuration as the program
-takes it, inputs and weights from the seed, and the first steps of a job
-read on the program's side and on the reference's.
+"""What both training drivers share: the optimizer's configuration as the
+program takes it, inputs and keys from the seed, and the first steps of a
+job read on the program's side and on the reference family's (the
+family's own pieces, the model's configuration as the program takes it
+among them, are in ``references/<family>.py``).
 
 The program's readings (taken by a driver from its own first steps) and
 the reference's (``reference_readings``) have one shape: the loss of each
@@ -11,7 +13,6 @@ steps.  ``compare.gaps`` turns two of them into the numbers that decide
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import Any, Callable, Dict, List, Optional
 
@@ -53,35 +54,8 @@ def tokens_per_step(w: Dict[str, Any]) -> int:
 
 
 # --------------------------------------------------------------------------- #
-# the program's view of the configuration
+# the program's view of the optimizer, and the layout check
 # --------------------------------------------------------------------------- #
-
-
-def arch_config(c: Dict[str, Any]):
-    """The program's ArchConfig for configuration file ``c``: its registered
-    architecture with every size the file states."""
-    from repro.configs import MLAConfig, MoEConfig, get_arch
-
-    cfg = get_arch(c["arch"])
-    kw: Dict[str, Any] = dict(
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"], n_kv_heads=c["num_key_value_heads"],
-        d_head=c.get("head_dim"), d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], norm_eps=c["rms_norm_eps"],
-        rope_theta=c["rope_theta"], tie_embeddings=c["tie_word_embeddings"],
-        param_dtype=c["param_dtype"], compute_dtype=c["compute_dtype"])
-    if reference.is_moe(c):
-        kw["moe"] = MoEConfig(
-            n_experts=c["num_local_experts"], top_k=c["num_experts_per_tok"],
-            capacity_factor=c["moe_capacity_factor"],
-            group_size=c["moe_group_size"],
-            router_aux_weight=c["router_aux_loss_coef"])
-    if reference.is_mla(c):
-        kw["mla"] = MLAConfig(
-            q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
-            qk_nope_dim=c["qk_nope_head_dim"], qk_rope_dim=c["qk_rope_head_dim"],
-            v_head_dim=c["v_head_dim"])
-    return dataclasses.replace(cfg, **kw)
 
 
 def adamw_config(o: Dict[str, Any]):
@@ -89,13 +63,14 @@ def adamw_config(o: Dict[str, Any]):
     return AdamWConfig(**{k: o[k] for k in AdamWConfig._fields})
 
 
-def check_layout(model, params) -> None:
-    """The weights the benchmark made must be the tree the program takes."""
+def check_layout(model, params, c: Dict[str, Any]) -> None:
+    """The weights that configuration ``c``'s reference family made must be
+    the tree the program takes."""
     want = jax.tree.map(lambda s: (tuple(s.shape), str(s.dtype)), model.param_struct())
     got = jax.tree.map(lambda x: (tuple(x.shape), str(x.dtype)), params)
     if want != got:
-        raise SystemExit("the program's parameter layout differs from the "
-                         "benchmark's weights; update the config's reference")
+        raise SystemExit("the program's parameter layout differs from the benchmark's "
+                         f"weights; update references/{c['reference']}.py")
 
 
 # --------------------------------------------------------------------------- #
@@ -158,30 +133,22 @@ def fingerprint(tree):
 # --------------------------------------------------------------------------- #
 
 
-def trained_part(params, trained):
-    """What trains: the whole tree (``"all"``), or the named parts of the
-    last layer with the final norm (a list such as ``["attn", "ln1"]``)."""
-    if trained == "all":
-        return params
-    last = reference.layer_slice(params, -1)
-    return {"last": {k: last[k] for k in trained}, "ln_f": params["ln_f"]}
-
-
-def reference_readings(c: Dict[str, Any], o: Dict[str, Any], params_key,
+def reference_readings(family, c: Dict[str, Any], o: Dict[str, Any], params_key,
                        batch_fn: Callable[[int], Dict[str, Any]], n_steps: int,
                        trained, dot=reference.exact_dot) -> Readings:
-    """Run ``n_steps`` AdamW steps of the reference from the seed's weights
-    on ``batch_fn(i)`` (i = 0 .. n_steps-1); ``trained`` as in
-    ``trained_part``.  Float32 at the highest precision unless ``dot`` says
+    """Run ``n_steps`` AdamW steps of configuration ``c``'s reference family
+    (``registry.reference``) from the seed's weights on ``batch_fn(i)``
+    (i = 0 .. n_steps-1); ``trained`` as the family's ``trained_part``
+    takes it.  Float32 at the highest precision unless ``dot`` says
     otherwise."""
-    params = reference.init_params(params_key, c)
-    tr0 = trained_part(params, trained)
+    params = family.init_params(params_key, c)
+    tr0 = family.trained_part(params, trained)
     if trained == "all":
         def loss_fn(tr, frozen, b):
-            return reference.loss(tr, b["tokens"], b["labels"], c, dot)
+            return family.loss(tr, b["tokens"], b["labels"], c, dot)
     else:
         def loss_fn(tr, frozen, b):
-            return reference.loss(frozen, b["tokens"], b["labels"], c, dot, trained=tr)
+            return family.loss(frozen, b["tokens"], b["labels"], c, dot, trained=tr)
     grad_fn = jax.jit(jax.value_and_grad(loss_fn))
     adam = jax.jit(functools.partial(reference.adamw, o), static_argnames=("count",))
     tr = tr0
